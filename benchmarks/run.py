@@ -10,6 +10,8 @@ import argparse
 import sys
 import traceback
 
+from repro.runtime.compile_cache import enable_compile_cache
+
 QUICK_SUITES = ["fig10", "fig12", "fig13"]
 
 
@@ -20,6 +22,7 @@ def main() -> None:
                     help="CI smoke subset (~30 s)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from . import (table1_overall, fig7_scaling, fig8_density, fig9_beam,
                    fig10_kernel, fig11_streaming, fig12_batch,

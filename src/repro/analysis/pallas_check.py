@@ -102,6 +102,12 @@ def _index_map_moves(block_mapping) -> bool:
     return any(int(a) != int(b) for a, b in zip(at0, at1))
 
 
+def _block_dim(d) -> int:
+    """One declared block dim: a `pl.Blocked`/`pl.Squeezed` or a plain int."""
+    size = getattr(d, "block_size", d)
+    return int(size) if isinstance(size, int) else 1   # squeezed: one row
+
+
 def harvest_pallas_calls(closed) -> list[KernelSummary]:
     """Every `pallas_call` in a traced jaxpr, as `KernelSummary` objects."""
     out = []
@@ -114,10 +120,10 @@ def harvest_pallas_calls(closed) -> list[KernelSummary]:
         n_out = len(eqn.outvars)
         for i, bm in enumerate(gm.block_mappings):
             label = f"in[{i}]" if i < n_in else f"out[{i - n_in}]"
-            sd = bm.array_shape_dtype
+            sd = bm.array_aval
             blocks.append(BlockInfo(
                 label=label,
-                block_shape=tuple(int(d) for d in bm.block_shape),
+                block_shape=tuple(_block_dim(d) for d in bm.block_shape),
                 array_shape=tuple(int(d) for d in sd.shape),
                 dtype=np.dtype(sd.dtype).name,
                 streamed=_index_map_moves(bm)))

@@ -44,7 +44,7 @@ def _cache_size(fn) -> int | None:
 
 
 def supported() -> bool:
-    """Whether this jax exposes `jit._cache_size()` (0.4.x does)."""
+    """Whether this jax exposes `jit._cache_size()` (0.9.0 does)."""
     return _cache_size(_decoder._jit_decode(VanillaSpec())) is not None
 
 

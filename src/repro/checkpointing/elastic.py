@@ -20,8 +20,8 @@ def abstract_target_mesh(axis_sizes, axis_names):
 
     `plan_rescale` only reads ``mesh.shape``, so a scheduler planning a
     shrink/grow on a login host passes the result of this instead of a real
-    `Mesh`.  Goes through `runtime.jaxcompat` because `AbstractMesh`'s
-    constructor signature differs between jax 0.4.x and current jax.
+    `Mesh`.  Goes through `runtime.jaxcompat`, the one call site for
+    `AbstractMesh`'s constructor (it has changed between jax releases).
     """
     return abstract_mesh(axis_sizes, axis_names)
 

@@ -29,9 +29,8 @@ is the serving configuration: whole sequences shard across devices and decode
 through `core.batch.viterbi_decode_batch`, inheriting its ragged-`lengths`
 contract (pad frames are tropical-identity steps — no pad mass in scores).
 
-All `shard_map` use goes through `runtime.jaxcompat`, which bridges the
-jax 0.4.x / current-jax API drift (shard_map location, check_rep/check_vma);
-this module must keep importing and running on both.
+All `shard_map` use goes through `runtime.jaxcompat`, the one call site for
+jax's shard_map / mesh API (written for jax 0.9.0).
 """
 
 from __future__ import annotations

@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 exposes this dataclass as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _SENTINEL = -4.0e9
 
 
@@ -143,7 +140,7 @@ def beam_step(log_A: jax.Array, em_t: jax.Array, scores: jax.Array,
             pltpu.VMEM((B,), jnp.int32),
             pltpu.VMEM((B,), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(log_A, em_t, scores, states)

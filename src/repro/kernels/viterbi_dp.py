@@ -38,8 +38,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 exposes this dataclass as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# Per-sequence operands carry a unit axis so every block's last two dims are
+# legal on the chip: (bt, 1) for the pad flags (1 == the full lane axis) and
+# (1, K) for the delta rows (1 == the full sublane axis, K lane-aligned).  A
+# (1, bt) / (1, K) block over a (B, T) / (B, K) array is refused by Mosaic.
+
+def _tiled_operands(em, delta0, pad):
+    """(B, T, 1) pad flags and (B, 1, K) initial deltas for the kernels."""
+    B, T, K = em.shape
+    if pad is None:
+        pad = jnp.zeros((B, T), em.dtype)
+    return pad.astype(em.dtype)[:, :, None], delta0[:, None, :]
+
+
+def _pad_spec(bt: int):
+    return pl.BlockSpec((1, bt, 1), lambda b, ti: (b, ti, 0))
+
+
+def _row_spec(K: int):
+    return pl.BlockSpec((1, 1, K), lambda b, ti: (b, 0, 0))
+
+
+def _out_shape(B: int, T: int, K: int, dtype):
+    return [jax.ShapeDtypeStruct((B, T, K), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, K), dtype)]
 
 
 def _viterbi_fwd_kernel(a_ref, em_ref, pad_ref, d0_ref, psi_ref, dT_ref, dscr,
@@ -48,7 +70,7 @@ def _viterbi_fwd_kernel(a_ref, em_ref, pad_ref, d0_ref, psi_ref, dT_ref, dscr,
 
     @pl.when(ti == 0)
     def _seed():                             # new sequence: re-seed the carry
-        dscr[0, :] = d0_ref[0, :]
+        dscr[0, :] = d0_ref[0, 0, :]
 
     log_a = a_ref[...]                       # (K, K), resident
     K = log_a.shape[0]
@@ -58,7 +80,7 @@ def _viterbi_fwd_kernel(a_ref, em_ref, pad_ref, d0_ref, psi_ref, dT_ref, dscr,
         scores = delta[:, None] + log_a      # (K_src, K_dst)
         psi = jnp.argmax(scores, axis=0).astype(jnp.int32)
         new = jnp.max(scores, axis=0) + em_ref[0, s, :]
-        is_pad = pad_ref[0, s] > 0.5         # tropical-identity step
+        is_pad = pad_ref[0, s, 0] > 0.5      # tropical-identity step
         psi_ref[0, s, :] = jnp.where(is_pad, eye, psi)
         return jnp.where(is_pad, delta, new)
 
@@ -67,7 +89,7 @@ def _viterbi_fwd_kernel(a_ref, em_ref, pad_ref, d0_ref, psi_ref, dT_ref, dscr,
 
     @pl.when(ti == nsteps - 1)
     def _emit():
-        dT_ref[0, :] = delta
+        dT_ref[0, 0, :] = delta
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -89,32 +111,28 @@ def viterbi_forward_batch(log_A: jax.Array, em: jax.Array, delta0: jax.Array,
     B, T, K = em.shape
     assert T % bt == 0, (T, bt)
     nsteps = T // bt
-    if pad is None:
-        pad = jnp.zeros((B, T), em.dtype)
-    pad = pad.astype(em.dtype)
+    pad3, d03 = _tiled_operands(em, delta0, pad)
 
-    return pl.pallas_call(
+    psi, dT = pl.pallas_call(
         functools.partial(_viterbi_fwd_kernel, bt=bt, nsteps=nsteps),
         grid=(B, nsteps),
         in_specs=[
             pl.BlockSpec((K, K), lambda b, ti: (0, 0)),      # resident
             pl.BlockSpec((1, bt, K), lambda b, ti: (b, ti, 0)),  # streamed
-            pl.BlockSpec((1, bt), lambda b, ti: (b, ti)),
-            pl.BlockSpec((1, K), lambda b, ti: (b, 0)),
+            _pad_spec(bt),
+            _row_spec(K),
         ],
         out_specs=[
             pl.BlockSpec((1, bt, K), lambda b, ti: (b, ti, 0)),  # streamed out
-            pl.BlockSpec((1, K), lambda b, ti: (b, 0)),
+            _row_spec(K),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, K), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), em.dtype),
-        ],
+        out_shape=_out_shape(B, T, K, em.dtype),
         scratch_shapes=[pltpu.VMEM((1, K), em.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(log_A, em, pad, delta0)
+    )(log_A, em, pad3, d03)
+    return psi, dT[:, 0]
 
 
 def _viterbi_fwd_masked_kernel(*refs, bt: int, nsteps: int, has_tmask: bool,
@@ -144,7 +162,7 @@ def _viterbi_fwd_masked_kernel(*refs, bt: int, nsteps: int, has_tmask: bool,
 
     @pl.when(ti == 0)
     def _seed():
-        dscr[0, :] = d0_ref[0, :]
+        dscr[0, :] = d0_ref[0, 0, :]
 
     log_a = a_ref[...]
     if has_tmask:
@@ -159,7 +177,7 @@ def _viterbi_fwd_masked_kernel(*refs, bt: int, nsteps: int, has_tmask: bool,
         if has_smask:
             em_t = em_t + sm_ref[s, :]
         new = jnp.max(scores, axis=0) + em_t
-        is_pad = pad_ref[0, s] > 0.5
+        is_pad = pad_ref[0, s, 0] > 0.5
         psi_ref[0, s, :] = jnp.where(is_pad, eye, psi)
         return jnp.where(is_pad, delta, new)
 
@@ -168,7 +186,7 @@ def _viterbi_fwd_masked_kernel(*refs, bt: int, nsteps: int, has_tmask: bool,
 
     @pl.when(ti == nsteps - 1)
     def _emit():
-        dT_ref[0, :] = delta
+        dT_ref[0, 0, :] = delta
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -193,9 +211,7 @@ def viterbi_forward_batch_masked(log_A: jax.Array, em: jax.Array,
     B, T, K = em.shape
     assert T % bt == 0, (T, bt)
     nsteps = T // bt
-    if pad is None:
-        pad = jnp.zeros((B, T), em.dtype)
-    pad = pad.astype(em.dtype)
+    pad3, d03 = _tiled_operands(em, delta0, pad)
     has_tmask = tmask is not None
     has_smask = smask is not None
 
@@ -209,28 +225,25 @@ def viterbi_forward_batch_masked(log_A: jax.Array, em: jax.Array,
     if has_smask:
         inputs.append(smask)
         in_specs.append(pl.BlockSpec((bt, K), lambda b, ti: (ti, 0)))
-    inputs += [pad, delta0]
-    in_specs += [pl.BlockSpec((1, bt), lambda b, ti: (b, ti)),
-                 pl.BlockSpec((1, K), lambda b, ti: (b, 0))]
+    inputs += [pad3, d03]
+    in_specs += [_pad_spec(bt), _row_spec(K)]
 
-    return pl.pallas_call(
+    psi, dT = pl.pallas_call(
         functools.partial(_viterbi_fwd_masked_kernel, bt=bt, nsteps=nsteps,
                           has_tmask=has_tmask, has_smask=has_smask),
         grid=(B, nsteps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bt, K), lambda b, ti: (b, ti, 0)),
-            pl.BlockSpec((1, K), lambda b, ti: (b, 0)),
+            _row_spec(K),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, T, K), jnp.int32),
-            jax.ShapeDtypeStruct((B, K), em.dtype),
-        ],
+        out_shape=_out_shape(B, T, K, em.dtype),
         scratch_shapes=[pltpu.VMEM((1, K), em.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*inputs)
+    return psi, dT[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -253,19 +266,6 @@ def viterbi_forward(log_A: jax.Array, em: jax.Array, delta0: jax.Array,
         bt=bt, interpret=interpret)
     return psi[0], delta_T[0]
 
-
-#: flashprove waivers (see analysis/findings.py for the grammar).
-FLASHPROVE_WAIVERS = {
-    "PV201:pallas:viterbi_dp.viterbi_forward_batch": (
-        "the (1, bt) pad-mask block streams bt per-step flags (32 B at the "
-        "default bt=8) next to the (bt, K) emission block; its lane padding "
-        "costs one tile of bandwidth per grid step, immaterial against the "
-        "bt x K emission stream it rides with"),
-    "PV201:pallas:viterbi_dp.viterbi_forward_batch_masked": (
-        "same (1, bt) pad-mask block as viterbi_forward_batch (32 B at the "
-        "default bt=8 against the bt x K emission + penalty streams); the "
-        "penalty blocks themselves are lane-aligned (K multiple of 128)"),
-}
 
 __all__ = ["viterbi_forward", "viterbi_forward_batch",
            "viterbi_forward_batch_masked"]

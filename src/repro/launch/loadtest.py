@@ -56,6 +56,7 @@ from repro.core import (ResourceBudget, erdos_renyi_hmm, plan,
 from repro.core import reference as ref
 from repro.core.hmm import HMM
 from repro.core.spec import DecodeSpec, OnlineSpec
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.alignment import make_alignment_head
 from repro.serving.scheduler import BatchScheduler
 from repro.serving.stream import StreamMux
@@ -843,6 +844,7 @@ def main(argv=None):
     ap.add_argument("--inflight-out", default=DEFAULT_INFLIGHT_OUT)
     ap.add_argument("--out", default=DEFAULT_OUT)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     overrides = {}
     if args.interarrival_us is not None:

@@ -5,9 +5,8 @@ Multi-pod:  2 x 16 x 16 = 512 chips, axes (pod, data, model) — the pod axis
 carries only data parallelism / ZeRO reduce-scatter (DCI-friendly); no TP
 collective crosses pods.
 
-Meshes are built through `runtime.jaxcompat.make_mesh`, which passes
-``AxisType.Auto`` only on jax versions that have it — this module must import
-and run on the pinned 0.4.x toolchain as well as current jax.
+Meshes are built through `runtime.jaxcompat.make_mesh` (``AxisType.Auto`` on
+every axis), the one call site for jax's mesh API.
 
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import).

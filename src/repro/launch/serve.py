@@ -6,9 +6,9 @@
     # or let the planner pick (method, P, B) from a memory budget:
     PYTHONPATH=src python -m repro.launch.serve --requests 32 --budget-kb 64
 
-Spins up the encoder (smoke-sized hubert on CPU), a left-to-right HMM, the
-alignment head, and the batching scheduler; reports latency and
-relative-error stats per request batch.  With ``--budget-kb`` the decode spec
+Builds a left-to-right HMM, the alignment head and the batching scheduler,
+serves seeded random emissions (no encoder runs here), and reports
+throughput and the relative error against an exact decode.  With ``--budget-kb`` the decode spec
 comes from `core.planner.plan` — the budget covers the live DP state of a
 full ``--max-batch`` bucket at the largest length bucket, which is the
 paper's adaptivity story running end-to-end in the serving path.
@@ -17,6 +17,7 @@ paper's adaptivity story running end-to-end in the serving path.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -25,10 +26,22 @@ import numpy as np
 
 from repro.core import (left_to_right_hmm, viterbi_vanilla, relative_error,
                         plan, ResourceBudget)
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.alignment import AlignmentConfig, make_alignment_head
 from repro.serving.scheduler import BatchScheduler
 
 BUCKETS = (128, 256, 512)
+
+
+@dataclasses.dataclass
+class ServeResult:
+    """What one `main` run served: completed requests, the HMM and spec they
+    were decoded with, the drain's wall seconds, and the scheduler stats."""
+    done: list
+    hmm: object
+    spec: object
+    wall_s: float
+    stats: dict
 
 
 def main(argv=None):
@@ -46,6 +59,7 @@ def main(argv=None):
                          "planner")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     key = jax.random.key(args.seed)
     k_hmm, key = jax.random.split(key)
@@ -86,7 +100,8 @@ def main(argv=None):
           f"mean pad frac={np.mean(sched.stats['padded_frac']):.2f}")
     print(f"relative error vs exact (sample of 8): "
           f"mean={np.mean(errs):.2e} max={np.max(errs):.2e}")
-    return done
+    return ServeResult(done=done, hmm=hmm, spec=spec, wall_s=wall,
+                       stats=sched.stats)
 
 
 if __name__ == "__main__":

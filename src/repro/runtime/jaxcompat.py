@@ -1,105 +1,44 @@
-"""Version-portable shims over the jax APIs that drift across releases.
+"""The one call site for the jax mesh / shard_map APIs (written for jax 0.9.0).
 
-This repo runs on the pinned internal toolchain (jax 0.4.37) *and* on current
-jax.  Three API surfaces the distributed layer depends on moved between those
-versions, and every call site used to hardcode one side of the move — which is
-how the whole subsystem went dark on 0.4.x.  This module bridges all three:
+Three API surfaces the distributed layer depends on have moved between jax
+releases before, and every call site used to hardcode one spelling — which is
+how the whole subsystem once went dark.  Routing them through this module
+keeps the next move a one-file change (flashlint FL001 enforces it):
 
-  * ``shard_map`` — lives at ``jax.shard_map`` on new jax but only under
-    ``jax.experimental.shard_map`` on 0.4.x, and the replication-check kwarg
-    was renamed ``check_rep`` -> ``check_vma``.
-  * ``make_mesh`` — ``jax.make_mesh`` grew an ``axis_types`` kwarg, and
-    ``jax.sharding.AxisType`` itself only exists on newer jax.
-  * ``abstract_mesh`` — ``jax.sharding.AbstractMesh`` changed its constructor
-    from a ``((name, size), ...)`` shape tuple (0.4.x) to positional
-    ``(axis_sizes, axis_names)`` (current).
+  * ``shard_map`` — ``jax.shard_map``, replication check ``check_vma``.
+  * ``make_mesh`` — ``jax.make_mesh`` with ``axis_types=(AxisType.Auto, ...)``.
+  * ``abstract_mesh`` — ``jax.sharding.AbstractMesh(axis_sizes, axis_names)``.
 
-Feature probes run exactly once, at import time; call sites branch on the
-resulting module-level booleans instead of sniffing jax versions.  Importing
-this module never touches jax device state (the dry-runs set ``XLA_FLAGS``
-before the first device query, and must keep working).
+Importing this module never touches jax device state (the dry-runs set
+``XLA_FLAGS`` before the first device query, and must keep working).
 """
 
 from __future__ import annotations
 
-import inspect
-import re
-
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
-# ---------------------------------------------------------------------------
-# Feature probes (once, at import)
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-#: shard_map takes ``check_vma`` (new) rather than ``check_rep`` (0.4.x).
-HAS_CHECK_VMA = "check_vma" in inspect.signature(_shard_map_impl).parameters
-
-#: jax.sharding.AxisType exists (explicit-sharding-aware meshes).
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-#: jax.make_mesh accepts ``axis_types``.
-HAS_MAKE_MESH_AXIS_TYPES = hasattr(jax, "make_mesh") and (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-#: AbstractMesh uses the old ``shape_tuple`` of (name, size) pairs (0.4.x).
-ABSTRACT_MESH_TAKES_PAIRS = (
-    "shape_tuple" in inspect.signature(AbstractMesh.__init__).parameters)
-
-
-def jax_version() -> tuple[int, ...]:
-    """The installed jax version as an int tuple, e.g. ``(0, 4, 37)``.
-
-    Tolerates pre-release / dev suffixes ("0.5.0rc1", "0.4.38.dev20240101"):
-    each dot segment contributes its leading digits.
-    """
-    parts = []
-    for p in jax.__version__.split(".")[:3]:
-        m = re.match(r"\d+", p)
-        parts.append(int(m.group()) if m else 0)
-    return tuple(parts)
-
-
-# ---------------------------------------------------------------------------
-# Portable constructors / wrappers
-# ---------------------------------------------------------------------------
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = False):
-    """`shard_map` that runs on 0.4.x and current jax.
+    """`jax.shard_map` with the replication check named portably.
 
-    ``check_replication`` maps onto ``check_vma`` (new) or ``check_rep``
-    (0.4.x); both default False here because the distributed decoders combine
-    shards with explicit collectives (pmax / all_gather) whose replication
-    the static checker cannot always prove.
+    ``check_replication`` maps onto ``check_vma``; it defaults False here
+    because the distributed decoders combine shards with explicit collectives
+    (pmax / all_gather) whose replication the static checker cannot always
+    prove.
     """
-    kwarg = "check_vma" if HAS_CHECK_VMA else "check_rep"
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{kwarg: check_replication})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_replication)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """`jax.make_mesh` passing ``AxisType.Auto`` only where supported."""
-    if HAS_MAKE_MESH_AXIS_TYPES and HAS_AXIS_TYPE:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=axis_types)
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-    # pre-make_mesh jax: reshape the flat device list by hand
-    import numpy as np
-    devs = list(jax.devices()) if devices is None else list(devices)
-    n = int(np.prod(axis_shapes))
-    return Mesh(np.asarray(devs[:n]).reshape(axis_shapes), axis_names)
+    """`jax.make_mesh` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """Device-free `AbstractMesh` under either constructor signature.
+    """Device-free `AbstractMesh`.
 
     Use this to describe a *target* topology (e.g. for elastic-rescale
     planning) on hosts that do not have the devices — only axis names and
@@ -110,13 +49,7 @@ def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
     if len(axis_sizes) != len(axis_names):
         raise ValueError(
             f"axis_sizes {axis_sizes} and axis_names {axis_names} disagree")
-    if ABSTRACT_MESH_TAKES_PAIRS:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
     return AbstractMesh(axis_sizes, axis_names)
 
 
-__all__ = [
-    "shard_map", "make_mesh", "abstract_mesh", "jax_version",
-    "HAS_CHECK_VMA", "HAS_AXIS_TYPE", "HAS_MAKE_MESH_AXIS_TYPES",
-    "ABSTRACT_MESH_TAKES_PAIRS",
-]
+__all__ = ["shard_map", "make_mesh", "abstract_mesh"]

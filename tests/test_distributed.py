@@ -1,9 +1,8 @@
 """Distributed tests (8 fake devices, run in a subprocess so the forced device
 count never leaks into other tests' jax runtime).
 
-Everything here must collect and pass on the pinned jax 0.4.x toolchain AND
-current jax — mesh construction and every shard_map goes through
-`repro.runtime.jaxcompat`.  CI runs this file in a dedicated step with
+Mesh construction and every shard_map go through `repro.runtime.jaxcompat`,
+the one call site for jax's mesh API.  CI runs this file in a dedicated step with
 ``--xla_force_host_platform_device_count=8`` (``make test-dist``)."""
 
 import json
@@ -33,8 +32,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 out = {}
 
-# 0. mesh construction through the compat shim on stock jax (this was the
-#    import-time regression: jax.sharding.AxisType does not exist on 0.4.x)
+# 0. mesh construction through the compat shim (once an import-time
+#    regression, when a newer-jax symbol was spelled at the call site)
 mesh = make_test_mesh()   # (4, 2) data x model
 out["mesh_import_and_build"] = (len(mesh.devices.ravel()) == 8 and
                                 data_axis_size(mesh) == 4)
@@ -146,7 +145,7 @@ def results():
 
 
 def test_mesh_builds_on_stock_jax(results):
-    """Regression: launch/mesh.py imports + builds meshes on jax 0.4.x."""
+    """Regression: launch/mesh.py imports + builds meshes through the shim."""
     assert results["mesh_import_and_build"]
     assert results["mesh_multipod_build"]
 

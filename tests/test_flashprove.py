@@ -75,7 +75,7 @@ def test_crosscheck_rejects_an_ir_blowup():
 # ---------------------------------------------------------------------------
 
 def test_injected_f64_promotion_is_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.ones((8,), jnp.float32))
         _, findings = analyze_jaxpr(closed, "jaxpr:injected", 1 << 20)

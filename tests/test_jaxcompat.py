@@ -1,36 +1,11 @@
-"""Unit tests for `repro.runtime.jaxcompat` — these run on a single device
-and on any supported jax version; the probes themselves are the contract."""
+"""Unit tests for `repro.runtime.jaxcompat` — these run on a single device."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 from repro.runtime import jaxcompat as jc
-
-
-def test_probes_are_booleans():
-    for flag in (jc.HAS_CHECK_VMA, jc.HAS_AXIS_TYPE,
-                 jc.HAS_MAKE_MESH_AXIS_TYPES, jc.ABSTRACT_MESH_TAKES_PAIRS):
-        assert isinstance(flag, bool)
-
-
-def test_probes_consistent_with_installed_jax():
-    # the kwarg rename and jax.shard_map promotion happened together with the
-    # AxisType introduction; on 0.4.x all three must be absent
-    if jc.jax_version() < (0, 5, 0):
-        assert not jc.HAS_AXIS_TYPE
-        assert not jc.HAS_MAKE_MESH_AXIS_TYPES
-        assert jc.ABSTRACT_MESH_TAKES_PAIRS
-    assert jc.HAS_AXIS_TYPE == hasattr(jax.sharding, "AxisType")
-
-
-def test_jax_version_tuple():
-    v = jc.jax_version()
-    assert isinstance(v, tuple) and len(v) == 3
-    assert all(isinstance(p, int) for p in v)
-    assert v >= (0, 4, 0)
 
 
 def test_make_mesh_single_device():

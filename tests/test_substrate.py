@@ -125,8 +125,8 @@ def test_heartbeat_and_straggler():
 
 def test_elastic_plan_rescale():
     # abstract target mesh: plan_rescale only reads shapes (1-device test
-    # host); constructed through the jaxcompat shim — AbstractMesh's
-    # signature differs between jax 0.4.x and current jax
+    # host); constructed through the jaxcompat shim, the one call site for
+    # AbstractMesh's constructor
     mesh_ok = abstract_target_mesh((2, 2), ("data", "model"))
     shapes = {"w": jax.ShapeDtypeStruct((64, 128), jnp.float32)}
     specs = {"w": P("data", "model")}
