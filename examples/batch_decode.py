@@ -79,5 +79,5 @@ ok = all(
     for i, r in enumerate(done))
 print(f"scheduler results == batched decode: {ok}")
 print(f"scheduler stats: {sched.stats['batches']} batch(es), "
-      f"mean pad frac {np.mean(sched.stats['padded_frac']):.2f} "
+      f"pad frac {sched.pad_frac():.2f} "
       f"-- padding costs throughput only, never correctness")

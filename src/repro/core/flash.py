@@ -166,7 +166,9 @@ def _flash_padded(log_pi, log_A, em, pad, P: int, lanes: int | None):
     seg0 = Tp // P
 
     boundaries = (np.arange(1, P) * seg0 - 1).astype(np.int64)  # e_i, i < P-1
-    q_bounds, q_last, score = _initial_pass(log_pi, log_A, em, pad, boundaries)
+    with jax.named_scope("flash.initial_pass"):
+        q_bounds, q_last, score = _initial_pass(log_pi, log_A, em, pad,
+                                                boundaries)
 
     q_star = jnp.zeros((Tp,), dtype=jnp.int32)
     q_star = q_star.at[Tp - 1].set(q_last)
@@ -174,22 +176,23 @@ def _flash_padded(log_pi, log_A, em, pad, P: int, lanes: int | None):
         q_star = q_star.at[jnp.asarray(boundaries)].set(q_bounds)
 
     s = seg0
-    while s >= 2:  # layer wavefront: L = log2(seg0) layers, statically unrolled
-        n = Tp // s
-        starts = np.arange(n, dtype=np.int64) * s
-        ends = starts + s - 1
-        mids = starts + s // 2 - 1
-        em_tiles = em.reshape(n, s, K)
-        pad_tiles = pad.reshape(n, s)
-        entries = q_star[jnp.asarray(np.maximum(starts - 1, 0))]
-        exits = q_star[jnp.asarray(ends)]
-        is_first = jnp.asarray(starts == 0)
+    with jax.named_scope("flash.wavefront"):
+        while s >= 2:  # L = log2(seg0) layers, statically unrolled
+            n = Tp // s
+            starts = np.arange(n, dtype=np.int64) * s
+            ends = starts + s - 1
+            mids = starts + s // 2 - 1
+            em_tiles = em.reshape(n, s, K)
+            pad_tiles = pad.reshape(n, s)
+            entries = q_star[jnp.asarray(np.maximum(starts - 1, 0))]
+            exits = q_star[jnp.asarray(ends)]
+            is_first = jnp.asarray(starts == 0)
 
-        fn = partial(_segment_decode, log_pi, log_A)
-        mid_states = chunked_vmap(
-            fn, (em_tiles, pad_tiles, entries, exits, is_first), lanes)
-        q_star = q_star.at[jnp.asarray(mids)].set(mid_states)
-        s //= 2
+            fn = partial(_segment_decode, log_pi, log_A)
+            mid_states = chunked_vmap(
+                fn, (em_tiles, pad_tiles, entries, exits, is_first), lanes)
+            q_star = q_star.at[jnp.asarray(mids)].set(mid_states)
+            s //= 2
     return q_star, score
 
 

@@ -189,7 +189,8 @@ def viterbi_decode_fused(log_pi: jax.Array, log_A: jax.Array, em: jax.Array,
         q_prev = psi_t[q].astype(jnp.int32)
         return q_prev, q_prev
 
-    _, prefix = jax.lax.scan(back, q_last, psi, reverse=True)
+    with jax.named_scope("viterbi.backtrack"):
+        _, prefix = jax.lax.scan(back, q_last, psi, reverse=True)
     return jnp.concatenate([prefix, q_last[None]]), delta_T[q_last]
 
 
@@ -228,7 +229,8 @@ def viterbi_decode_fused_batch(log_pi: jax.Array, log_A: jax.Array,
         _, prefix = jax.lax.scan(back, q, psis, reverse=True)
         return prefix
 
-    prefix = jax.vmap(back_one)(q_last, psi)
+    with jax.named_scope("viterbi.backtrack"):
+        prefix = jax.vmap(back_one)(q_last, psi)
     paths = jnp.concatenate([prefix, q_last[:, None]], axis=1)
     scores = jnp.take_along_axis(delta_T, q_last[:, None], axis=1)[:, 0]
     return paths, scores

@@ -420,9 +420,7 @@ class LoadHarness:
                            "elapsed_s": elapsed},
             "latency_s": {k: _pct(v) for k, v in self.latency.items()},
             "scheduler": {"batches": self.sched.stats["batches"],
-                          "mean_pad_frac":
-                              float(np.mean(self.sched.stats["padded_frac"]))
-                              if self.sched.stats["padded_frac"] else 0.0},
+                          "pad_frac": self.sched.pad_frac()},
             "stream": {**{k: int(v) for k, v in self.mux.stats.items()},
                        "peak_live_state_bytes": int(self.peak_stream_bytes),
                        "commit_lag_frames": _pct(self.lag_frames)},
@@ -888,7 +886,7 @@ def main(argv=None):
     print(f"  offline latency p50={off['p50'] * 1e3:.1f}ms "
           f"p99={off['p99'] * 1e3:.1f}ms; "
           f"batches={report['scheduler']['batches']}, "
-          f"pad frac={report['scheduler']['mean_pad_frac']:.2f}")
+          f"pad frac={report['scheduler']['pad_frac']:.2f}")
     failed = False
     if "oracle" in report:
         print(f"  oracle: offline {report['oracle']['offline']['checked']} "

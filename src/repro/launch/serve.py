@@ -97,7 +97,7 @@ def main(argv=None):
         errs.append(float(relative_error(opt, r.result[1])))
     print(f"served {len(done)} requests in {wall:.2f}s "
           f"({len(done)/wall:.1f} req/s), batches={sched.stats['batches']}, "
-          f"mean pad frac={np.mean(sched.stats['padded_frac']):.2f}")
+          f"pad frac={sched.pad_frac():.2f}")
     print(f"relative error vs exact (sample of 8): "
           f"mean={np.mean(errs):.2e} max={np.max(errs):.2e}")
     return ServeResult(done=done, hmm=hmm, spec=spec, wall_s=wall,

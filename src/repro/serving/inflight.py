@@ -60,6 +60,7 @@ from repro.core.planner import (AdmissionPlan, inflight_state_bytes,
                                 online_session_bytes, plan_admission)
 from repro.core.spec import OnlineSpec, ResourceBudget
 from repro.kernels.ops import viterbi_slot_step
+from repro.runtime.spans import span
 
 __all__ = ["InflightScheduler", "AdmissionRejected", "inflight_jit_fns"]
 
@@ -205,9 +206,11 @@ class InflightScheduler:
         self._admitted_bytes = 0
         self._ids = itertools.count()
         self._step_s: list[float] = []
+        # finish_steps: the steps `finish()` ran to drain a session
         self.stats = {"opened": 0, "finished": 0, "steps": 0, "frames": 0,
                       "commits": 0, "degraded": 0, "queued_peak": 0,
-                      "overflow_finishes": 0, "rejected": 0}
+                      "overflow_finishes": 0, "rejected": 0,
+                      "finish_steps": 0}
 
     # -- admission ----------------------------------------------------------
     def _remaining_bytes(self) -> int | None:
@@ -365,51 +368,65 @@ class InflightScheduler:
 
         Slots with nothing ready ride along as tropical-identity steps —
         their delta comes back bit-identical.  Returns counters.
+
+        Four spans (`runtime.spans`, ``step=`` the step number) name the
+        work in order: ``inflight.stage`` (pick the ready slots, fill the
+        host staging arrays), ``inflight.dispatch`` (upload and enqueue the
+        kernel), ``inflight.psi_copy`` (until psi is on the host: the wait
+        for the kernel and the copy) and ``inflight.commit`` (the commit
+        scan).  The wait has no span of its own: splitting it off takes a
+        second host sync, which cost time on the chip; the device trace
+        shows where the kernel ends.
         """
-        plans: list[tuple[_Session, int]] = []
-        for sess in self._sessions.values():
-            c = self._consume_now(sess)
-            if c:
-                plans.append((sess, c))
-        if not plans:
-            return {"advanced": 0, "frames": 0, "committed": 0}
-        t0 = self._clock()
-        for sess, c in plans:
-            s = sess.slot
-            frames = sess.take(c)
-            if not sess.seeded:
-                self._em0[s] = frames[0]
-                self._fresh[s] = True
-                rows = frames[1:]
-            else:
-                rows = frames
-            n = int(rows.shape[0])
-            if n:
-                self._em[s, :n] = rows
-            self._nfeed[s] = n
-        psi, self._delta = _inflight_step(
-            self.log_pi, self.log_A, jnp.asarray(self._em0),
-            jnp.asarray(self._fresh), jnp.asarray(self._em), self._delta,
-            jnp.asarray(self._nfeed), bt=self.bt)
-        psi_np = np.asarray(psi)          # one batched transfer per step
+        n_step = self.stats["steps"]
+        with span("inflight.stage", step=n_step):
+            plans: list[tuple[_Session, int]] = []
+            for sess in self._sessions.values():
+                c = self._consume_now(sess)
+                if c:
+                    plans.append((sess, c))
+            if not plans:
+                return {"advanced": 0, "frames": 0, "committed": 0}
+            t0 = self._clock()
+            for sess, c in plans:
+                s = sess.slot
+                frames = sess.take(c)
+                if not sess.seeded:
+                    self._em0[s] = frames[0]
+                    self._fresh[s] = True
+                    rows = frames[1:]
+                else:
+                    rows = frames
+                n = int(rows.shape[0])
+                if n:
+                    self._em[s, :n] = rows
+                self._nfeed[s] = n
+        with span("inflight.dispatch", step=n_step):
+            psi, self._delta = _inflight_step(
+                self.log_pi, self.log_A, jnp.asarray(self._em0),
+                jnp.asarray(self._fresh), jnp.asarray(self._em), self._delta,
+                jnp.asarray(self._nfeed), bt=self.bt)
+        with span("inflight.psi_copy", step=n_step):
+            psi_np = np.asarray(psi)          # one batched transfer per step
         frames_run = 0
         committed = 0
-        for sess, c in plans:
-            s = sess.slot
-            if not sess.seeded:
-                sess.seeded = True
-                sess.dec.seed()
-                self._fresh[s] = False
-            n = int(self._nfeed[s])
-            self._nfeed[s] = 0
-            frames_run += c
-            if n:
-                out = sess.dec.ingest(psi_np[s, :n])
-                if out.shape[0]:
-                    sess.pending.append(out)
-                    committed += int(out.shape[0])
-                    if sess.t_first_commit is None:
-                        sess.t_first_commit = self._clock()
+        with span("inflight.commit", step=n_step):
+            for sess, c in plans:
+                s = sess.slot
+                if not sess.seeded:
+                    sess.seeded = True
+                    sess.dec.seed()
+                    self._fresh[s] = False
+                n = int(self._nfeed[s])
+                self._nfeed[s] = 0
+                frames_run += c
+                if n:
+                    out = sess.dec.ingest(psi_np[s, :n])
+                    if out.shape[0]:
+                        sess.pending.append(out)
+                        committed += int(out.shape[0])
+                        if sess.t_first_commit is None:
+                            sess.t_first_commit = self._clock()
         self._step_s.append(self._clock() - t0)
         self.stats["steps"] += 1
         self.stats["frames"] += frames_run
@@ -444,7 +461,9 @@ class InflightScheduler:
         sess.draining = True
         while sess.buffered:
             self.step()
-        tail, score = sess.dec.flush()
+            self.stats["finish_steps"] += 1
+        with span("inflight.flush", sid=sid):
+            tail, score = sess.dec.flush()
         if tail.shape[0]:
             sess.pending.append(tail)
         sess.final = (sess.dec.path, score)

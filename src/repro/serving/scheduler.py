@@ -21,7 +21,10 @@ import time
 from collections import deque
 from typing import Any, Callable
 
+import jax
 import numpy as np
+
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass
@@ -51,7 +54,10 @@ class BatchScheduler:
         self.buckets = sorted(buckets)
         self.queue: deque[Request] = deque()
         self._next_id = itertools.count()
-        self.stats = {"batches": 0, "requests": 0, "padded_frac": []}
+        # frames: real frames decoded; padded_frames: pad frames added to
+        # fill buckets (both summed over every batch)
+        self.stats = {"batches": 0, "requests": 0, "frames": 0,
+                      "padded_frames": 0}
 
     def submit(self, payload) -> Request:
         req = Request(rid=next(self._next_id), payload=payload,
@@ -66,34 +72,54 @@ class BatchScheduler:
         return self.buckets[-1]
 
     def step(self) -> list[Request]:
-        """Run one batch; returns completed requests."""
+        """Run one batch; returns completed requests.
+
+        Four spans (`runtime.spans`, ``batch=`` the batch number) name the
+        work in order: ``batch.pad`` (pick and pad), ``batch.dispatch``
+        (enqueue the decode), ``batch.wait`` (until the device is done)
+        and ``batch.unpad`` (the per-row fan-out).
+        """
         if not self.queue:
             return []
-        first = self.queue[0]
-        bucket = self._bucket(len(first.payload))
-        batch: list[Request] = []
-        rest: deque[Request] = deque()
-        while self.queue and len(batch) < self.max_batch:
-            r = self.queue.popleft()
-            if self._bucket(len(r.payload)) == bucket:
-                batch.append(r)
-            else:
-                rest.append(r)
-        self.queue.extendleft(reversed(rest))
+        n = self.stats["batches"]
+        with span("batch.pad", batch=n):
+            first = self.queue[0]
+            bucket = self._bucket(len(first.payload))
+            batch: list[Request] = []
+            rest: deque[Request] = deque()
+            while self.queue and len(batch) < self.max_batch:
+                r = self.queue.popleft()
+                if self._bucket(len(r.payload)) == bucket:
+                    batch.append(r)
+                else:
+                    rest.append(r)
+            self.queue.extendleft(reversed(rest))
 
-        lens = np.asarray([len(r.payload) for r in batch], np.int32)
-        K = batch[0].payload.shape[-1]
-        padded = np.zeros((len(batch), bucket, K), np.float32)
-        for i, r in enumerate(batch):
-            padded[i, :lens[i]] = r.payload  # pad tail masked by the decoder
-        paths, scores = self.fn(padded, lens)
-        for i, r in enumerate(batch):
-            r.result = (np.asarray(paths[i][:lens[i]]), float(scores[i]))
-            r.done = True
+            lens = np.asarray([len(r.payload) for r in batch], np.int32)
+            K = batch[0].payload.shape[-1]
+            padded = np.zeros((len(batch), bucket, K), np.float32)
+            for i, r in enumerate(batch):
+                padded[i, :lens[i]] = r.payload  # tail masked by the decoder
+        with span("batch.dispatch", batch=n):
+            paths, scores = self.fn(padded, lens)
+        with span("batch.wait", batch=n):
+            jax.block_until_ready((paths, scores))
+        with span("batch.unpad", batch=n):
+            for i, r in enumerate(batch):
+                r.result = (np.asarray(paths[i][:lens[i]]), float(scores[i]))
+                r.done = True
+        frames = int(lens.sum())
         self.stats["batches"] += 1
         self.stats["requests"] += len(batch)
-        self.stats["padded_frac"].append(1 - np.mean(lens) / bucket)
+        self.stats["frames"] += frames
+        self.stats["padded_frames"] += padded.shape[0] * bucket - frames
         return batch
+
+    def pad_frac(self) -> float:
+        """Pad frames over all frames decoded, frame-weighted over every
+        batch so far (0.0 before the first)."""
+        total = self.stats["frames"] + self.stats["padded_frames"]
+        return self.stats["padded_frames"] / total if total else 0.0
 
     def drain(self) -> list[Request]:
         done = []

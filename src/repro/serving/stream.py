@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import time
 
 import numpy as np
 
@@ -76,8 +75,6 @@ class StreamSession:
         self._buf: list[np.ndarray] = []
         self._buffered = 0
         self._final: tuple[np.ndarray, float] | None = None
-        self.opened = time.monotonic()
-        self.first_commit_s: float | None = None
         self.frames_in = 0
 
     def feed(self, frames) -> np.ndarray:
@@ -101,11 +98,8 @@ class StreamSession:
             rest = pending[n_blocks * self.block:]
             self._buf = [rest] if rest.shape[0] else []
             self._buffered = rest.shape[0]
-        committed = (np.concatenate(out) if out
-                     else np.zeros((0,), np.int32))
-        if committed.shape[0] and self.first_commit_s is None:
-            self.first_commit_s = time.monotonic() - self.opened
-        return committed
+        return (np.concatenate(out) if out
+                else np.zeros((0,), np.int32))
 
     def finish(self) -> tuple[np.ndarray, float]:
         """Drain the buffer, flush the decoder; returns (full path, score).
