@@ -77,7 +77,9 @@ class BatchScheduler:
         Four spans (`runtime.spans`, ``batch=`` the batch number) name the
         work in order: ``batch.pad`` (pick and pad), ``batch.dispatch``
         (enqueue the decode), ``batch.wait`` (until the device is done)
-        and ``batch.unpad`` (the per-row fan-out).
+        and ``batch.unpad`` (one copy of paths and scores to the host,
+        then per-row host slices).  Each request gets its own copy of its
+        row, so a result does not keep the batch's buffer alive.
         """
         if not self.queue:
             return []
@@ -105,8 +107,9 @@ class BatchScheduler:
         with span("batch.wait", batch=n):
             jax.block_until_ready((paths, scores))
         with span("batch.unpad", batch=n):
+            paths, scores = np.asarray(paths), np.asarray(scores)
             for i, r in enumerate(batch):
-                r.result = (np.asarray(paths[i][:lens[i]]), float(scores[i]))
+                r.result = (paths[i, :lens[i]].copy(), float(scores[i]))
                 r.done = True
         frames = int(lens.sum())
         self.stats["batches"] += 1
