@@ -26,10 +26,10 @@ benchmark numbers.  Phases (a)-(c) fail without the kernel; any mismatch or
 error exits non-zero.  The last line is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
-``--chips 4`` decodes one fused K=512 bucket of 16 with
-`ViterbiDecoder.decode_sharded` over a 4-device ``data`` mesh, compares it
-bit for bit with `decode_batch` on one device, and prints where each output's
-shards live.
+``--chips 4`` decodes one fused K=512 bucket of 16 through the serving
+head of a `DataPlacement` (`FusedSpec` on a 4-device ``data`` mesh, so
+`ViterbiDecoder.decode_sharded`), compares it bit for bit with
+`decode_batch` on one device, and prints where each output's shards live.
 """
 
 from __future__ import annotations
@@ -321,19 +321,19 @@ def phase_sharded(clock: CompileClock, seed: int, *, n_dev: int = 4,
                   K: int = 512, B: int = 16, T: int = 512) -> dict:
     import jax
     import numpy as np
-    from repro.core import FusedSpec, ViterbiDecoder, erdos_renyi_hmm
-    from repro.runtime.jaxcompat import make_mesh
+    from repro.core import DataPlacement, FusedSpec, erdos_renyi_hmm
+    from repro.serving.alignment import make_alignment_head
 
-    mesh = make_mesh((n_dev,), ("data",), devices=jax.devices()[:n_dev])
+    placement = DataPlacement.over_devices(FusedSpec(), n_dev)
     hmm = erdos_renyi_hmm(jax.random.key(seed + 4), K)
-    dec = ViterbiDecoder(FusedSpec(), hmm.log_pi, hmm.log_A)
+    head = make_alignment_head(hmm.log_pi, hmm.log_A, placement)
+    dec = head.decoder
     rng = np.random.default_rng(seed + 4)
     em = (2.0 * rng.standard_normal((B, T, K))).astype(np.float32)
     lengths = rng.integers(T // 4, T + 1, B).astype(np.int32)
 
     def sharded():
-        return jax.block_until_ready(
-            dec.decode_sharded(em, lengths, mesh=mesh))
+        return jax.block_until_ready(head(em, lengths))
 
     _, (s_paths, s_scores), t = clock.cold_then_warm(sharded)
     one_paths, one_scores = jax.device_get(dec.decode_batch(em, lengths))
@@ -353,8 +353,7 @@ def phase_sharded(clock: CompileClock, seed: int, *, n_dev: int = 4,
     s_paths, s_scores = jax.device_get((s_paths, s_scores))
     bitwise = (s_paths.tobytes() == one_paths.tobytes()
                and s_scores.tobytes() == one_scores.tobytes())
-    text = jax.jit(lambda e, ln: dec.decode_sharded(e, ln, mesh=mesh)
-                   ).lower(em, lengths).compile().as_text()
+    text = jax.jit(head).lower(em, lengths).compile().as_text()
     return _report({
         "phase": "sharded4", "spec": repr(dec.spec),
         "shapes": f"K={K} B={B} T={T} mesh=data:{n_dev}",

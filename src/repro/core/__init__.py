@@ -24,7 +24,7 @@ from .spec import (ResourceBudget, DecodeSpec, VanillaSpec, CheckpointSpec,
 from .planner import (decoder_state_bytes, spec_state_bytes, DecodePlan, plan,
                       online_session_bytes, inflight_state_bytes,
                       AdmissionPlan, plan_admission)
-from .decoder import ViterbiDecoder
+from .decoder import DataPlacement, ViterbiDecoder
 from .api import (viterbi_decode, viterbi_decode_hmm, viterbi_decode_batch,
                   METHODS, BATCH_METHODS)
 
@@ -49,7 +49,7 @@ __all__ = [
     "decoder_state_bytes", "spec_state_bytes", "DecodePlan", "plan",
     "online_session_bytes", "inflight_state_bytes",
     "AdmissionPlan", "plan_admission",
-    "ViterbiDecoder",
+    "ViterbiDecoder", "DataPlacement",
     # legacy string dispatch (thin shim over the specs)
     "viterbi_decode", "viterbi_decode_hmm", "viterbi_decode_batch",
     "METHODS", "BATCH_METHODS",

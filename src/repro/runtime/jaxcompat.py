@@ -1,6 +1,6 @@
 """The one call site for the jax mesh / shard_map APIs (written for jax 0.9.0).
 
-Three API surfaces the distributed layer depends on have moved between jax
+The API surfaces the distributed layer depends on have moved between jax
 releases before, and every call site used to hardcode one spelling — which is
 how the whole subsystem once went dark.  Routing them through this module
 keeps the next move a one-file change (flashlint FL001 enforces it):
@@ -8,6 +8,7 @@ keeps the next move a one-file change (flashlint FL001 enforces it):
   * ``shard_map`` — ``jax.shard_map``, replication check ``check_vma``.
   * ``make_mesh`` — ``jax.make_mesh`` with ``axis_types=(AxisType.Auto, ...)``.
   * ``abstract_mesh`` — ``jax.sharding.AbstractMesh(axis_sizes, axis_names)``.
+  * ``is_traced`` — ``isinstance(x, jax.core.Tracer)``.
 
 Importing this module never touches jax device state (the dry-runs set
 ``XLA_FLAGS`` before the first device query, and must keep working).
@@ -29,6 +30,12 @@ def shard_map(f, *, mesh, in_specs, out_specs, check_replication: bool = False):
     """
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                          check_vma=check_replication)
+
+
+def is_traced(x) -> bool:
+    """Whether `x` is an abstract value being traced (inside jit or
+    shard_map) rather than a concrete array."""
+    return isinstance(x, jax.core.Tracer)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:

@@ -18,8 +18,9 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.core import (ViterbiDecoder, as_decode_spec, spec_from_tunables,
-                        LexiconConstraint, with_constraint)
+from repro.core import (DataPlacement, ViterbiDecoder, as_decode_spec,
+                        spec_from_tunables, LexiconConstraint,
+                        with_constraint)
 from repro.core.hmm import HMM
 
 
@@ -48,7 +49,8 @@ def make_alignment_head(hmm_log_pi, hmm_log_A, cfg, *,
                         mesh=None, data_axis: str = "data"):
     """Returns align(emissions (B, T, K), lengths=None) -> (paths, scores).
 
-    `cfg` is a `DecodeSpec` (preferred) or a legacy `AlignmentConfig`.
+    `cfg` is a `DecodeSpec` (preferred), a `DataPlacement` (a spec with the
+    data mesh it is served on) or a legacy `AlignmentConfig`.
 
     `lengths` (B,) gives each request's true frame count; pad frames run as
     tropical-identity steps inside `viterbi_decode_batch`, so results are
@@ -56,11 +58,18 @@ def make_alignment_head(hmm_log_pi, hmm_log_A, cfg, *,
     methods; FLASH-BS keeps its beam approximation but no pad corruption).
     This is the `decode_batch_fn` contract `BatchScheduler` expects.
 
-    With ``mesh=`` the request bucket shards over ``data_axis`` via
-    `ViterbiDecoder.decode_sharded`, which pads non-divisible bucket sizes
-    with length-1 dummy rows and slices back — per-request results are
-    unaffected (vmap lanes never interact).
+    With ``mesh=``, or a placement and no ``mesh=``, the request bucket
+    shards over the data axis via `ViterbiDecoder.decode_sharded`, which
+    pads non-divisible bucket sizes with length-1 dummy rows and slices
+    back — per-request results are unaffected (vmap lanes never interact).
+    ``mesh=`` stays the one place a mesh enters; a placement only carries
+    its own there, and a placement given with ``mesh=`` is refused.
     """
+    if isinstance(cfg, DataPlacement):
+        if mesh is not None:
+            raise ValueError("give the mesh once: a DataPlacement carries "
+                             "its own, so pass no mesh= with it")
+        mesh, data_axis = cfg.mesh, cfg.data_axis
     spec = as_decode_spec(cfg)
     dec = ViterbiDecoder(spec, hmm_log_pi, hmm_log_A)
 

@@ -20,7 +20,7 @@ import pytest
 
 from repro.core import (
     erdos_renyi_hmm, random_emissions, viterbi_decode, viterbi_decode_batch,
-    ViterbiDecoder, DecodePlan, plan, ResourceBudget,
+    ViterbiDecoder, DataPlacement, DecodePlan, plan, ResourceBudget,
     decoder_state_bytes, spec_state_bytes, spec_from_tunables,
     SPEC_BY_METHOD, METHODS, BATCH_METHODS,
     VanillaSpec, CheckpointSpec, FlashSpec, FlashBSSpec, BeamStaticSpec,
@@ -268,6 +268,68 @@ def test_decode_sharded_bit_identical(problem, method):
     assert p_sh.shape == p_ref.shape                 # dummies sliced back off
     assert np.array_equal(np.asarray(p_ref), np.asarray(p_sh))
     assert np.array_equal(np.asarray(s_ref), np.asarray(s_sh))
+
+
+@pytest.mark.parametrize("xp", [np, jnp], ids=["host", "device"])
+def test_data_placement_serves_through_decode_sharded(problem, xp):
+    """A placement passes as a spec; the head built from it shards over its
+    mesh (here one device) and matches `decode_batch` bit for bit, from a
+    host batch and from one already on the device."""
+    from repro.core import as_decode_spec
+    from repro.serving.alignment import make_alignment_head
+    hmm, em = problem
+    T = em.shape[0]
+    placement = DataPlacement.over_devices(FusedSpec(), 1)
+    assert as_decode_spec(placement) == FusedSpec()
+    head = make_alignment_head(hmm.log_pi, hmm.log_A, placement)
+    ems = xp.stack([xp.asarray(em), xp.asarray(em)[::-1]])
+    lengths = xp.asarray([T, T // 3], xp.int32)
+    p_sh, s_sh = head(ems, lengths)
+    p_ref, s_ref = head.decoder.decode_batch(ems, lengths)
+    assert p_sh.sharding.mesh == placement.mesh
+    assert np.array_equal(np.asarray(p_ref), np.asarray(p_sh))
+    assert np.array_equal(np.asarray(s_ref), np.asarray(s_sh))
+    assert head.decoder.stats == {"sharded_batches": 1, "dummy_rows": 0}
+
+
+def test_data_placement_under_jit_counts_no_traces(problem):
+    """Jitting the placed head traces `decode_sharded` (the put becomes a
+    sharding constraint) and matches the eager call; only concrete calls
+    count as batches."""
+    from repro.serving.alignment import make_alignment_head
+    hmm, em = problem
+    T = em.shape[0]
+    head = make_alignment_head(hmm.log_pi, hmm.log_A,
+                               DataPlacement.over_devices(FusedSpec(), 1))
+    ems = jnp.stack([em, em * 0.5])
+    lengths = jnp.asarray([T, T // 2], jnp.int32)
+    p_jit, s_jit = jax.jit(head)(ems, lengths)
+    assert head.decoder.stats["sharded_batches"] == 0
+    p_eager, s_eager = head(ems, lengths)
+    assert np.array_equal(np.asarray(p_jit), np.asarray(p_eager))
+    assert np.array_equal(np.asarray(s_jit), np.asarray(s_eager))
+    assert head.decoder.stats["sharded_batches"] == 1
+
+
+def _head_with_placement_and_mesh():
+    from repro.serving.alignment import make_alignment_head
+    K = 128
+    make_alignment_head(jnp.zeros((K,)), jnp.zeros((K, K)),
+                        DataPlacement.over_devices(FusedSpec(), 1),
+                        mesh=make_mesh((1,), ("data",)))
+
+
+@pytest.mark.parametrize("bad", [
+    lambda: DataPlacement.over_devices(OnlineSpec(), 1),
+    lambda: DataPlacement.over_devices(FusedSpec(), 0),
+    lambda: DataPlacement.over_devices(FusedSpec(), len(jax.devices()) + 1),
+    lambda: DataPlacement(FusedSpec(), make_mesh((1,), ("data",)), "model"),
+    _head_with_placement_and_mesh,
+], ids=["not_batchable", "no_devices", "more_than_jax_sees", "no_such_axis",
+        "placement_and_mesh"])
+def test_data_placement_refuses(bad):
+    with pytest.raises(ValueError):
+        bad()
 
 
 def test_decode_batch_ragged_matches_single(problem):

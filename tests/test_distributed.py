@@ -98,6 +98,69 @@ for i in range(5):
     ok = ok and bool(np.isclose(float(hs[i]), float(s), rtol=1e-6))
 out["alignment_head_sharded_exact"] = ok
 
+# 4b. the offline serving path on a data placement (a 4-device sub-mesh):
+#     BatchScheduler over the placed head, ragged lengths over two buckets,
+#     partial batches that need dummy rows
+from repro.core import DataPlacement, FusedSpec, ViterbiDecoder
+from repro.serving.scheduler import BatchScheduler
+Kp = 128
+hmm_p = erdos_renyi_hmm(jax.random.key(11), Kp, edge_prob=0.3)
+lp_np, la_np = np.asarray(hmm_p.log_pi), np.asarray(hmm_p.log_A)
+placed = DataPlacement.over_devices(FusedSpec(), 4)
+head_p = make_alignment_head(hmm_p.log_pi, hmm_p.log_A, placed)
+lens_p = [16, 9, 31, 32, 5, 20, 32, 17, 12, 27, 3]     # buckets 16 and 32
+ems_p = np.asarray(random_emissions(jax.random.key(12), len(lens_p) * 32,
+                                    Kp)).reshape(len(lens_p), 32, Kp)
+puts = []
+real_put = jax.device_put
+def spy_put(x, *a, **kw):
+    y = real_put(x, *a, **kw)
+    puts.append((x, y))
+    return y
+jax.device_put = spy_put
+try:
+    sched_p = BatchScheduler(head_p, max_batch=4, buckets=(16, 32))
+    reqs = [sched_p.submit(ems_p[i, :L]) for i, L in enumerate(lens_p)]
+    dummies = 0
+    while sched_p.queue:
+        dummies += -len(sched_p.step()) % 4
+finally:
+    jax.device_put = real_put
+one_chip = ViterbiDecoder(FusedSpec(), hmm_p.log_pi, hmm_p.log_A)
+identical = reference_ok = True
+for bucket in (16, 32):
+    idx = [i for i, L in enumerate(lens_p) if (L <= 16) == (bucket == 16)]
+    lens_b = np.asarray([lens_p[i] for i in idx], np.int32)
+    pb, sb = one_chip.decode_batch(ems_p[idx, :bucket], lens_b)
+    pb, sb = np.asarray(pb), np.asarray(sb)
+    for j, i in enumerate(idx):
+        L = lens_p[i]
+        path, score = reqs[i].result
+        identical = identical and bool(np.array_equal(path, pb[j, :L])) \
+            and np.float32(score).tobytes() == sb[j].tobytes()
+        rpath, rscore = ref.viterbi_numpy(lp_np, la_np, ems_p[i, :L])
+        reference_ok = reference_ok and bool(np.array_equal(path, rpath)) \
+            and abs(score - rscore) <= 1e-5 * abs(rscore)
+out["placed_scheduler_bit_identical"] = identical and all(r.done for r in reqs)
+out["placed_scheduler_matches_reference"] = reference_ok
+# each put of the batch goes from the host straight to the four devices,
+# every device holding its own rows
+em_puts = [(x, y) for x, y in puts if getattr(x, "ndim", 0) == 3]
+on_own = bool(em_puts)
+for x, y in em_puts:
+    shards = sorted(y.addressable_shards, key=lambda sh: sh.index[0].start)
+    rows = x.shape[0] // 4
+    on_own = on_own and isinstance(x, np.ndarray) \
+        and len({sh.device for sh in shards}) == 4 \
+        and all(np.array_equal(np.asarray(sh.data), x[k * rows:(k + 1) * rows])
+                for k, sh in enumerate(shards))
+out["placed_put_each_shard_on_its_device"] = on_own
+stats_p = head_p.decoder.stats
+out["placed_dummy_rows_counted"] = (dummies > 0
+                                    and stats_p["dummy_rows"] == dummies
+                                    and stats_p["sharded_batches"]
+                                    == len(em_puts))
+
 # 5. smoke train step actually runs SPMD on the test mesh (not just lowers)
 cfg = get_arch("tinyllama_1_1b").SMOKE
 from repro.models import build_model
@@ -172,6 +235,24 @@ def test_sharded_batch_matches_single_device(results):
 
 def test_alignment_head_sharded(results):
     assert results["alignment_head_sharded_exact"]
+
+
+def test_placed_scheduler_bit_identical_to_one_chip(results):
+    """BatchScheduler over a head on a 4-device data placement: every
+    answer equals the one-chip decode_batch bit for bit."""
+    assert results["placed_scheduler_bit_identical"]
+
+
+def test_placed_scheduler_matches_reference(results):
+    assert results["placed_scheduler_matches_reference"]
+
+
+def test_placed_put_sends_each_device_its_rows(results):
+    assert results["placed_put_each_shard_on_its_device"]
+
+
+def test_placed_dummy_rows_counted(results):
+    assert results["placed_dummy_rows_counted"]
 
 
 def test_spmd_train_step_runs_and_learns(results):

@@ -101,3 +101,28 @@ def test_fused_batch_decode_compiles(chip):
     text = _compiled_text(chip, decode, ((K,), F32), ((K, K), F32),
                           ((B, T, K), F32), ((B,), I32))
     assert "tpu_custom_call" in text
+
+
+def test_sharded_fused_batch_decode_compiles(topo, monkeypatch):
+    # the program's sharded decode (`core.batch._sharded_decoder`, the
+    # shard_map `ViterbiDecoder.decode_sharded` runs) over the four
+    # described chips, 64 rows each; `ops` sees the CPU backend, so the test
+    # steers its kernels to compile
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import FusedSpec
+    from repro.core.batch import _sharded_decoder
+    from repro.runtime.jaxcompat import make_mesh
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    K, B, T = 512, 256, 512
+    mesh = make_mesh((4,), ("data",), devices=topo.devices[:4])
+    spec = FusedSpec()
+    decode = _sharded_decoder(mesh, "data", spec.batch_method, tuple(sorted(
+        dict(spec.batch_tunables(), constraint=None).items())))
+    repl, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((K,), F32, sharding=repl),
+            jax.ShapeDtypeStruct((K, K), F32, sharding=repl),
+            jax.ShapeDtypeStruct((B, T, K), F32, sharding=rows),
+            jax.ShapeDtypeStruct((B,), I32, sharding=rows)]
+    text = decode.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
