@@ -42,6 +42,16 @@ class BatchScheduler:
     `decode_batch_fn` is either the raw callable contract above, or a
     `core.ViterbiDecoder` — the scheduler then drives its `decode_batch`
     (the decoder owns jit caching per bucket shape and the lengths contract).
+
+    Batches are padded into a host staging buffer kept for each
+    ``(bucket, K)`` served, ``(max_batch, bucket, K)`` float32, allocated the
+    first time that bucket is padded and reused by every later batch of it:
+    the scheduler holds up to ``max_batch * bucket * K * 4`` bytes of host
+    memory for each bucket it has served.  The padded batch handed to
+    `decode_batch_fn` is a leading view of that buffer and is valid only for
+    the duration of the call; a callable that keeps it must copy it.
+    ``stats["staging_allocs"]`` counts the buffers allocated,
+    ``stats["staging_reuses"]`` the batches padded into one that existed.
     """
 
     def __init__(self, decode_batch_fn, max_batch: int = 8,
@@ -54,10 +64,13 @@ class BatchScheduler:
         self.buckets = sorted(buckets)
         self.queue: deque[Request] = deque()
         self._next_id = itertools.count()
+        # (bucket, K) -> (staging buffer, frames last written into each slot)
+        self._staging: dict[tuple, tuple[np.ndarray, np.ndarray]] = {}
         # frames: real frames decoded; padded_frames: pad frames added to
         # fill buckets (both summed over every batch)
         self.stats = {"batches": 0, "requests": 0, "frames": 0,
-                      "padded_frames": 0}
+                      "padded_frames": 0, "staging_allocs": 0,
+                      "staging_reuses": 0}
 
     def submit(self, payload) -> Request:
         req = Request(rid=next(self._next_id), payload=payload,
@@ -75,18 +88,27 @@ class BatchScheduler:
         """Run one batch; returns completed requests.
 
         Four spans (`runtime.spans`, ``batch=`` the batch number) name the
-        work in order: ``batch.pad`` (pick and pad), ``batch.dispatch``
+        work in order: ``batch.pad`` (pick and pad; ``reused=`` 1 when the
+        bucket's staging buffer already existed, else 0), ``batch.dispatch``
         (enqueue the decode), ``batch.wait`` (until the device is done)
         and ``batch.unpad`` (one copy of paths and scores to the host,
         then per-row host slices).  Each request gets its own copy of its
         row, so a result does not keep the batch's buffer alive.
+
+        Each row is written into its slot of the bucket's staging buffer,
+        and only the frames a longer earlier row left past its length are
+        zeroed, so every pad frame the decoder sees is 0.0.  The buffer is
+        written again only after ``batch.wait``, when the outputs, which
+        depend on it, are ready.
         """
         if not self.queue:
             return []
         n = self.stats["batches"]
-        with span("batch.pad", batch=n):
-            first = self.queue[0]
-            bucket = self._bucket(len(first.payload))
+        first = self.queue[0]
+        bucket = self._bucket(len(first.payload))
+        key = (bucket, first.payload.shape[-1])
+        staged = self._staging.get(key)
+        with span("batch.pad", batch=n, reused=int(staged is not None)):
             batch: list[Request] = []
             rest: deque[Request] = deque()
             while self.queue and len(batch) < self.max_batch:
@@ -98,10 +120,21 @@ class BatchScheduler:
             self.queue.extendleft(reversed(rest))
 
             lens = np.asarray([len(r.payload) for r in batch], np.int32)
-            K = batch[0].payload.shape[-1]
-            padded = np.zeros((len(batch), bucket, K), np.float32)
+            if staged is None:
+                staged = self._staging[key] = (
+                    np.zeros((self.max_batch,) + key, np.float32),
+                    np.zeros(self.max_batch, np.int32))
+                self.stats["staging_allocs"] += 1
+            else:
+                self.stats["staging_reuses"] += 1
+            buf, filled = staged
             for i, r in enumerate(batch):
-                padded[i, :lens[i]] = r.payload  # tail masked by the decoder
+                L = lens[i]
+                buf[i, :L] = r.payload  # tail masked by the decoder
+                if filled[i] > L:
+                    buf[i, L:filled[i]] = 0.0
+                filled[i] = L
+            padded = buf[:len(batch)]
         with span("batch.dispatch", batch=n):
             paths, scores = self.fn(padded, lens)
         with span("batch.wait", batch=n):

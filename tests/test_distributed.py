@@ -115,7 +115,8 @@ puts = []
 real_put = jax.device_put
 def spy_put(x, *a, **kw):
     y = real_put(x, *a, **kw)
-    puts.append((x, y))
+    # the scheduler reuses its staging buffer: keep a copy of what was put
+    puts.append((x.copy() if isinstance(x, np.ndarray) else x, y))
     return y
 jax.device_put = spy_put
 try:

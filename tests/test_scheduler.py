@@ -130,3 +130,105 @@ def test_results_equal_the_batched_call_rows_and_own_their_memory(decoder):
     for i, a in enumerate(reqs):
         for b in reqs[i + 1:]:
             assert not np.shares_memory(a.result[0], b.result[0])
+
+
+# -- staging buffers --------------------------------------------------------
+#
+# Each (bucket, K) is padded into one kept (max_batch, bucket, K) buffer;
+# only the stale part of each slot's tail is zeroed between batches.
+
+
+class _Staged:
+    """A decode_batch_fn that enforces the padding contract on every call
+    (rows intact, every pad frame exactly 0.0) and records each call's
+    shape and the address of the array it was handed, keeping no
+    reference to the array."""
+
+    def __init__(self):
+        self.calls = []                       # (shape, data address)
+
+    def __call__(self, padded, lengths):
+        B, Tb, _ = padded.shape
+        lengths = np.asarray(lengths)
+        tags = padded[:, 0, 0].astype(np.int32)
+        for i in range(B):
+            assert np.all(padded[i, :lengths[i]] == tags[i])
+            assert np.all(padded[i, lengths[i]:] == 0.0), (i, lengths[i])
+        self.calls.append((padded.shape, padded.ctypes.data))
+        return np.repeat(tags[:, None], Tb, 1), tags.astype(np.float32)
+
+
+def _serve(sched, rows):
+    """Submit (T, K, tag) rows, drain, and check each result's tag."""
+    reqs = [sched.submit(np.full((T, K), tag, np.float32))
+            for T, K, tag in rows]
+    sched.drain()
+    for r, (T, _, tag) in zip(reqs, rows):
+        assert r.result[0].shape == (T,) and np.all(r.result[0] == tag)
+    return reqs
+
+
+def test_a_short_row_after_a_long_one_in_a_slot_sees_a_zero_tail():
+    dec = _Staged()
+    sched = BatchScheduler(dec, max_batch=2, buckets=(32,))
+    _serve(sched, [(32, 3, 1), (5, 3, 2)])        # slot 0 long, slot 1 short
+    _serve(sched, [(7, 3, 3), (30, 3, 4)])        # and the other way round
+    _serve(sched, [(1, 3, 5), (1, 3, 6)])
+    assert [shape for shape, _ in dec.calls] == [(2, 32, 3)] * 3
+    assert len({addr for _, addr in dec.calls}) == 1
+
+
+def test_a_partial_batch_hands_the_decoder_its_rows_only():
+    dec = _Staged()
+    sched = BatchScheduler(dec, max_batch=4, buckets=(16,))
+    _serve(sched, [(16, 2, t) for t in range(1, 7)])
+    _serve(sched, [(3, 2, 7)])
+    assert [shape for shape, _ in dec.calls] == [
+        (4, 16, 2), (2, 16, 2), (1, 16, 2)]
+    assert len({addr for _, addr in dec.calls}) == 1
+
+
+def test_buckets_and_state_counts_never_share_a_buffer():
+    dec = _Staged()
+    sched = BatchScheduler(dec, max_batch=2, buckets=(16, 32))
+    rows = [(16, 3, 1), (16, 3, 2), (32, 3, 3), (9, 5, 4)]
+    for _ in range(2):
+        _serve(sched, rows)
+    # calls alternate (16, K=3), (32, K=3), (16, K=5) twice over
+    addrs = [addr for _, addr in dec.calls]
+    assert len(addrs) == 6 and addrs[:3] == addrs[3:]
+    assert len(set(addrs)) == 3
+    spans = sorted((a, a + 2 * Tb * K * 4) for (_, Tb, K), a in dec.calls[:3])
+    assert all(hi <= lo for (_, hi), (lo, _) in zip(spans, spans[1:]))
+
+
+def test_staging_counters_count_one_alloc_per_bucket_and_state_count():
+    sched = BatchScheduler(_Staged(), max_batch=2, buckets=(16, 32))
+    assert sched.stats["staging_allocs"] == sched.stats["staging_reuses"] == 0
+    _serve(sched, [(10, 3, 1), (12, 3, 2), (20, 3, 3), (12, 4, 4)])
+    assert (sched.stats["staging_allocs"], sched.stats["staging_reuses"]) \
+        == (3, 0)
+    _serve(sched, [(16, 3, t) for t in range(1, 6)] + [(31, 3, 6)])
+    assert sched.stats["batches"] == 7
+    assert (sched.stats["staging_allocs"], sched.stats["staging_reuses"]) \
+        == (3, 4)
+
+
+def test_reused_buffers_decode_bit_identically_to_fresh_padding(decoder):
+    lengths = [24, 20, 3, 24, 1, 17, 11, 24, 2]
+    keys = jax.random.split(jax.random.key(11), len(lengths))
+    ems = [np.asarray(random_emissions(k, T, 16))
+           for k, T in zip(keys, lengths)]
+    sched = BatchScheduler(decoder, max_batch=3, buckets=(24,))
+    reqs = [sched.submit(em) for em in ems]
+    sched.drain()
+    assert sched.stats["staging_reuses"] == 2
+    for b in range(0, len(ems), 3):
+        padded = np.zeros((3, 24, 16), np.float32)
+        for i, em in enumerate(ems[b:b + 3]):
+            padded[i, :len(em)] = em
+        paths, scores = decoder.decode_batch(padded, lengths[b:b + 3])
+        for i, r in enumerate(reqs[b:b + 3]):
+            assert np.array_equal(r.result[0],
+                                  np.asarray(paths)[i, :lengths[b + i]])
+            assert r.result[1] == float(scores[i])

@@ -182,3 +182,19 @@ def test_single_sequence_fused_decode_scopes_its_backtrack():
 def test_offline_decode_program_keeps_its_name(spec):
     # the benchmark's rooflines match the decode program by this exact name
     assert re.match(r"HloModule jit__unknown,", _compiled(spec))
+
+
+def test_batch_pad_span_says_whether_the_staging_buffer_was_reused(
+        tmp_path):
+    sched = BatchScheduler(_decode, max_batch=2, buckets=(8, 16))
+    for T in (5, 7, 12, 3, 8, 16):
+        sched.submit(np.ones((T, 4), np.float32))
+    _decode(np.zeros((2, 8, 4), np.float32), np.zeros(2, np.int32))
+    _decode(np.zeros((2, 16, 4), np.float32), np.zeros(2, np.int32))
+    events = _recorded(tmp_path, sched.drain)
+    pads = [e[1] for e in events if e[0] == "repro.batch.pad"]
+    # batches of buckets 8, 16, 8: the first batch of each allocates
+    assert [(p["batch"], p["reused"]) for p in pads] == [
+        (0, 0), (1, 0), (2, 1)]
+    assert (sched.stats["staging_allocs"], sched.stats["staging_reuses"]) \
+        == (2, 1)
