@@ -1,1 +1,1 @@
-"""Benchmark harness: one module per paper table/figure + the roofline table."""
+"""Benchmark harness: one module per paper table/figure."""

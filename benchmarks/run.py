@@ -26,7 +26,7 @@ def main() -> None:
 
     from . import (table1_overall, fig7_scaling, fig8_density, fig9_beam,
                    fig10_kernel, fig11_streaming, fig12_batch,
-                   fig13_constrained, roofline_table)
+                   fig13_constrained)
     suites = {
         "table1": table1_overall.run,
         "fig7": fig7_scaling.run,
@@ -36,7 +36,6 @@ def main() -> None:
         "fig11": fig11_streaming.run,
         "fig12": fig12_batch.run,
         "fig13": fig13_constrained.run,
-        "roofline": roofline_table.run,
     }
     if args.only:
         picked = args.only.split(",")

@@ -1,64 +1,62 @@
-"""End-to-end serving driver (the paper's kind of workload): batched
-forced-alignment requests against a hubert-style encoder + FLASH-BS head.
+"""Forced-alignment serving: batched alignment requests through the serving head.
 
     PYTHONPATH=src python examples/forced_alignment_serving.py
+
+An acoustic model upstream of this head turns each utterance into per-frame
+log-posteriors over the states of its transcription's left-to-right HMM.
+No model runs here: seeded log-softmax emissions of shape (T, K) stand in
+for its output, as in `launch/serve.py`.  They are served as a deployment
+serves them: `make_alignment_head` with a FLASH-BS beam behind
+`BatchScheduler`, which buckets ragged lengths and pads them as
+tropical-identity steps.  Every alignment is checked to move only forward
+through the states, and its score against an exact decode.
 """
 
+import sys
 import time
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
-from repro.configs import get_arch
-from repro.models import build_model
-from repro.core import left_to_right_hmm
+from repro.core import (FlashBSSpec, VanillaSpec, ViterbiDecoder,
+                        left_to_right_hmm, relative_error)
+from repro.serving.alignment import make_alignment_head
 from repro.serving.scheduler import BatchScheduler
 
-# 1. encoder (reduced hubert on CPU; the full config runs on the pod)
-arch = get_arch("hubert_xlarge")
-cfg = arch.SMOKE
-model = build_model(cfg)
-key = jax.random.key(0)
-params = model.init(key)
-NUM_CLASSES = cfg.vocab
+K = 64                       # HMM states of the transcription
 
-# 2. alignment HMM over the transcription states (left-to-right)
-hmm = left_to_right_hmm(jax.random.key(1), 64, NUM_CLASSES)
+# 1. the alignment HMM over the transcription's states (left-to-right)
+hmm = left_to_right_hmm(jax.random.key(1), K, 16)
 
-# 3. one jitted serve step: encoder -> emissions -> FLASH-BS alignment.
-# `lengths` masks the bucket's pad frames as tropical-identity steps, so each
-# request decodes exactly as if it had been served alone.
-from repro.core import viterbi_decode_batch
+# 2. the serving head: FLASH-BS alignment behind the batching scheduler
+head = make_alignment_head(hmm.log_pi, hmm.log_A,
+                           FlashBSSpec(beam_width=32, parallelism=4,
+                                       lanes=None))
+sched = BatchScheduler(head, max_batch=4, buckets=(64,))
 
-@jax.jit
-def serve(frames, lengths):              # (B, T, d), (B,)
-    logits, _ = model.prefill(params, {"embeds": frames})
-    em = jax.nn.log_softmax(logits, axis=-1)
-    # map class posteriors onto HMM states (states index classes mod C)
-    state_to_class = jnp.arange(64) % NUM_CLASSES
-    em_states = em[..., state_to_class]  # (B, T, K_states)
-    return viterbi_decode_batch(em_states, hmm.log_pi, hmm.log_A, lengths,
-                                method="flash_bs", beam_width=32,
-                                parallelism=4, lanes=None)
-
-sched = BatchScheduler(
-    lambda b, lens: serve(jnp.asarray(b, cfg.dtype), jnp.asarray(lens)),
-    max_batch=4, buckets=(64,))
-
+# 3. requests: seeded per-frame state log-posteriors of ragged lengths
 rng = np.random.default_rng(0)
 for _ in range(12):
     T = int(rng.integers(40, 64))
-    sched.submit(rng.standard_normal((T, cfg.d_model)).astype(np.float32))
+    logits = 2.0 * rng.standard_normal((T, K)).astype(np.float32)
+    sched.submit(np.asarray(jax.nn.log_softmax(logits, axis=-1)))
 
 t0 = time.time()
 done = sched.drain()
 wall = time.time() - t0
 print(f"served {len(done)} alignment requests in {wall:.2f}s "
-      f"({len(done)/wall:.1f} req/s) in {sched.stats['batches']} batches")
+      f"({len(done)/wall:.1f} req/s) in {sched.stats['batches']} batches, "
+      f"pad frac {sched.pad_frac():.2f}")
 for r in done[:3]:
     path, score = r.result
     print(f"  req {r.rid}: frames={len(r.payload)} "
           f"alignment[0:12]={path[:12].tolist()} score={score:.1f}")
-print("alignment paths are monotone:",
-      all(np.all(np.diff(r.result[0]) >= 0) for r in done))
+
+exact = ViterbiDecoder(VanillaSpec(), hmm.log_pi, hmm.log_A)
+errs = [float(relative_error(exact.decode(r.payload)[1], r.result[1]))
+        for r in done]
+print(f"relative score error vs exact decode: mean {np.mean(errs):.2e}, "
+      f"max {np.max(errs):.2e}")
+monotone = all(np.all(np.diff(r.result[0]) >= 0) for r in done)
+print("alignment paths are monotone:", monotone)
+sys.exit(0 if monotone else 1)

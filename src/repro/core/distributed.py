@@ -21,8 +21,7 @@ Two orthogonal axes, composable on the production (data, model) mesh:
     (single-device argmax resolves to the smallest — path *scores* are
     invariant, asserted in tests).
 
-Per-step collective cost on the model axis: 2 x all-reduce of K floats/ints —
-this is what the roofline harness measures for the alignment-serving cell.
+Per-step collective cost on the model axis: 2 x all-reduce of K floats/ints.
 
 A third axis, **sequence parallelism over `data`** (`make_batched_flash_decoder`),
 is the serving configuration: whole sequences shard across devices and decode

@@ -1,15 +1,14 @@
-"""Production mesh construction.
+"""Test mesh construction for the distributed decode tests.
 
-Single pod: 16 x 16 = 256 chips, axes (data, model).
-Multi-pod:  2 x 16 x 16 = 512 chips, axes (pod, data, model) — the pod axis
-carries only data parallelism / ZeRO reduce-scatter (DCI-friendly); no TP
-collective crosses pods.
+`make_test_mesh` builds an 8-device (data, model) mesh, or a
+(pod, data, model) one, on forced host devices; `data_axis_size` is the
+number of data-parallel replicas of such a mesh.
 
 Meshes are built through `runtime.jaxcompat.make_mesh` (``AxisType.Auto`` on
 every axis), the one call site for jax's mesh API.
 
 Functions, not module-level constants: importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before any jax import).
+jax device state (callers set XLA_FLAGS before any jax import).
 """
 
 from __future__ import annotations
@@ -17,14 +16,8 @@ from __future__ import annotations
 from repro.runtime.jaxcompat import make_mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
 def make_test_mesh(*, multi_pod: bool = False):
-    """Small-device-count analogue for CI (8 fake devices)."""
+    """8 devices: (4, 2) data x model, or (2, 2, 2) pod x data x model."""
     shape = (2, 2, 2) if multi_pod else (4, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return make_mesh(shape, axes)
@@ -37,4 +30,4 @@ def data_axis_size(mesh) -> int:
     return size
 
 
-__all__ = ["make_production_mesh", "make_test_mesh", "data_axis_size"]
+__all__ = ["make_test_mesh", "data_axis_size"]

@@ -10,8 +10,8 @@ keeps the next move a one-file change (flashlint FL001 enforces it):
   * ``abstract_mesh`` — ``jax.sharding.AbstractMesh(axis_sizes, axis_names)``.
   * ``is_traced`` — ``isinstance(x, jax.core.Tracer)``.
 
-Importing this module never touches jax device state (the dry-runs set
-``XLA_FLAGS`` before the first device query, and must keep working).
+Importing this module never touches jax device state (the distributed tests
+set ``XLA_FLAGS`` before the first device query, and must keep working).
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
-"""Forced-alignment serving head: encoder emissions -> FLASH-BS Viterbi paths.
+"""Forced-alignment serving head: per-frame emissions -> Viterbi paths.
 
-This is the paper's workload running as a production operator: hubert-xlarge
-produces per-frame class posteriors (B, T, 504); a left-to-right HMM over the
-target transcription's states constrains the decode; FLASH-BS (dynamic beam)
-returns the per-frame alignment.
+This is the paper's workload running as a production operator: an acoustic
+model upstream of this head produces per-frame state log-posteriors
+(B, T, K); a left-to-right HMM over the target transcription's states
+constrains the decode; the head returns each request's per-frame alignment.
 
 The head is a thin wrapper around `core.ViterbiDecoder`: the alignment config
 resolves to a typed `DecodeSpec` (any batchable spec works — hand one in
@@ -15,13 +15,9 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax
-import jax.numpy as jnp
-
 from repro.core import (DataPlacement, ViterbiDecoder, as_decode_spec,
                         spec_from_tunables, LexiconConstraint,
                         with_constraint)
-from repro.core.hmm import HMM
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,33 +108,5 @@ def make_lexicon_align_head(hmm_log_pi, hmm_log_A, words, *, cfg=None,
     return align
 
 
-def make_e2e_align_step(model, params_treedef_hint, hmm: HMM,
-                        cfg, num_classes: int):
-    """Encoder forward + log-softmax emissions + Viterbi alignment, one jit.
-
-    The serving step for the hubert cells: batch {"embeds": (B, S, D)} ->
-    (paths (B, S), scores (B,)).  `cfg` is a `DecodeSpec` or legacy
-    `AlignmentConfig`.
-    """
-    spec = as_decode_spec(cfg)
-    if not spec.jittable:
-        raise ValueError(f"{type(spec).__name__} cannot run inside the "
-                         f"jitted e2e step; use an offline (jittable) spec")
-
-    def step(params, batch):
-        x = batch["embeds"]
-        # encoder forward reusing the model's loss-path stack
-        from repro.models.transformer import _run_stack
-        from repro.models.common import rms_norm
-        h, _, _ = _run_stack(model.cfg, params, x.astype(model.cfg.dtype),
-                             jnp.arange(x.shape[1]), collect_kv=False)
-        h = rms_norm(h, params["ln_out"])
-        logits = (h @ params["head"]).astype(jnp.float32)
-        em = jax.nn.log_softmax(logits[..., :num_classes], axis=-1)
-        return jax.vmap(lambda e: spec.run(hmm.log_pi, hmm.log_A, e))(em)
-
-    return step
-
-
 __all__ = ["AlignmentConfig", "make_alignment_head",
-           "make_lexicon_align_head", "make_e2e_align_step"]
+           "make_lexicon_align_head"]

@@ -24,11 +24,6 @@ from repro.core import (erdos_renyi_hmm, random_emissions, viterbi_decode,
 from repro.core import reference as ref
 from repro.core.distributed import make_flash_viterbi_2d, make_batched_flash_decoder
 from repro.launch.mesh import make_test_mesh, data_axis_size
-from repro.launch.steps import build_cell, lower_cell
-from repro.configs import get_arch
-from repro.sharding.rules import SINGLE_POD_RULES
-from repro.train import TrainConfig, init_train_state, make_train_step, train_state_specs
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 out = {}
 
@@ -111,12 +106,24 @@ head_p = make_alignment_head(hmm_p.log_pi, hmm_p.log_A, placed)
 lens_p = [16, 9, 31, 32, 5, 20, 32, 17, 12, 27, 3]     # buckets 16 and 32
 ems_p = np.asarray(random_emissions(jax.random.key(12), len(lens_p) * 32,
                                     Kp)).reshape(len(lens_p), 32, Kp)
-puts = []
+# each put of the batch goes from the host straight to the four devices,
+# every device holding its own rows.  Each put is checked as it happens: on
+# the CPU a put of an aligned host array aliases it, and the scheduler
+# rewrites its staging buffer for the next batch, so a shard read after the
+# drain may show a later batch's rows.
+em_puts = []
 real_put = jax.device_put
 def spy_put(x, *a, **kw):
     y = real_put(x, *a, **kw)
-    # the scheduler reuses its staging buffer: keep a copy of what was put
-    puts.append((x.copy() if isinstance(x, np.ndarray) else x, y))
+    if getattr(x, "ndim", 0) == 3:
+        shards = sorted(y.addressable_shards, key=lambda sh: sh.index[0].start)
+        rows = x.shape[0] // 4
+        em_puts.append(
+            isinstance(x, np.ndarray)
+            and len({sh.device for sh in shards}) == 4
+            and all(np.array_equal(np.asarray(sh.data),
+                                   x[k * rows:(k + 1) * rows])
+                    for k, sh in enumerate(shards)))
     return y
 jax.device_put = spy_put
 try:
@@ -144,55 +151,12 @@ for bucket in (16, 32):
             and abs(score - rscore) <= 1e-5 * abs(rscore)
 out["placed_scheduler_bit_identical"] = identical and all(r.done for r in reqs)
 out["placed_scheduler_matches_reference"] = reference_ok
-# each put of the batch goes from the host straight to the four devices,
-# every device holding its own rows
-em_puts = [(x, y) for x, y in puts if getattr(x, "ndim", 0) == 3]
-on_own = bool(em_puts)
-for x, y in em_puts:
-    shards = sorted(y.addressable_shards, key=lambda sh: sh.index[0].start)
-    rows = x.shape[0] // 4
-    on_own = on_own and isinstance(x, np.ndarray) \
-        and len({sh.device for sh in shards}) == 4 \
-        and all(np.array_equal(np.asarray(sh.data), x[k * rows:(k + 1) * rows])
-                for k, sh in enumerate(shards))
-out["placed_put_each_shard_on_its_device"] = on_own
+out["placed_put_each_shard_on_its_device"] = bool(em_puts) and all(em_puts)
 stats_p = head_p.decoder.stats
 out["placed_dummy_rows_counted"] = (dummies > 0
                                     and stats_p["dummy_rows"] == dummies
                                     and stats_p["sharded_batches"]
                                     == len(em_puts))
-
-# 5. smoke train step actually runs SPMD on the test mesh (not just lowers)
-cfg = get_arch("tinyllama_1_1b").SMOKE
-from repro.models import build_model
-model = build_model(cfg)
-tcfg = TrainConfig()
-with mesh:
-    state = init_train_state(model, jax.random.key(0))
-    specs = train_state_specs(model, SINGLE_POD_RULES, 4)
-    sh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
-                                is_leaf=lambda x: isinstance(x, P))
-    state = jax.tree_util.tree_map(jax.device_put, state, sh)
-    from repro.optim.adamw import AdamWConfig
-    tcfg = TrainConfig(opt=AdamWConfig(lr=5e-3, warmup_steps=1, total_steps=100))
-    step = jax.jit(make_train_step(model, tcfg), donate_argnums=0)
-    kt = jax.random.key(1)
-    batch = {"tokens": jax.random.randint(kt, (8, 16), 0, cfg.vocab),
-             "labels": jax.random.randint(kt, (8, 16), 0, cfg.vocab),
-             "mask": jnp.ones((8, 16))}
-    batch = jax.device_put(batch, NamedSharding(mesh, P("data", None)))
-    losses = []
-    for _ in range(6):
-        state, m = step(state, batch)
-        losses.append(float(m["loss"]))
-    out["spmd_train_losses_finite"] = all(np.isfinite(l) for l in losses)
-    out["spmd_train_loss_decreases"] = losses[-1] < losses[0]
-
-# 6. dry-run cell lowers+compiles on the 8-device mesh for a non-trivial arch
-with mesh:
-    cell = build_cell(get_arch("gemma_2b"), "decode_32k", mesh)
-    compiled = lower_cell(cell).compile()
-    out["gemma_decode_compiles"] = compiled is not None
 
 print("RESULT " + json.dumps(out))
 """
@@ -255,11 +219,3 @@ def test_placed_put_sends_each_device_its_rows(results):
 def test_placed_dummy_rows_counted(results):
     assert results["placed_dummy_rows_counted"]
 
-
-def test_spmd_train_step_runs_and_learns(results):
-    assert results["spmd_train_losses_finite"]
-    assert results["spmd_train_loss_decreases"]
-
-
-def test_dryrun_cell_compiles_on_test_mesh(results):
-    assert results["gemma_decode_compiles"]

@@ -1,8 +1,9 @@
-"""End-to-end behaviour tests: the paper's serving pipeline + training loop."""
+"""End-to-end behaviour tests: the paper's serving pipeline."""
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.core import (left_to_right_hmm, erdos_renyi_hmm,
                         viterbi_vanilla, relative_error)
@@ -36,48 +37,33 @@ def test_alignment_serving_end_to_end():
     assert np.mean(errs) < 0.05  # B=48/64 beam on random emissions
 
 
-def test_training_loop_loss_decreases(tmp_path):
-    """The end-to-end driver trains a tiny model and the loss goes down."""
-    from repro.launch.train import main
-    losses = main(["--arch", "tinyllama-1.1b", "--smoke", "--steps", "30",
-                   "--batch", "4", "--seq", "64", "--lr", "1e-2",
-                   "--ckpt-dir", str(tmp_path), "--ckpt-every", "10"])
-    assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0] - 0.3
-
-
-def test_training_resume_bitexact(tmp_path):
-    """Checkpoint/restart: resuming reproduces the uninterrupted run."""
-    from repro.launch.train import main
-    args = ["--batch", "2", "--seq", "32", "--lr", "1e-3", "--horizon", "10",
-            "--ckpt-every", "5", "--smoke", "--arch", "tinyllama-1.1b"]
-    full = main(["--steps", "10", "--ckpt-dir", str(tmp_path / "a")] + args)
-    part = main(["--steps", "5", "--ckpt-dir", str(tmp_path / "b")] + args)
-    resumed = main(["--steps", "10", "--resume",
-                    "--ckpt-dir", str(tmp_path / "b")] + args)
-    assert np.isfinite(full).all() and np.isfinite(resumed).all()
-    np.testing.assert_allclose(full[5:], resumed, rtol=2e-4, atol=2e-5)
-
-
-def test_scheduler_bit_identical_to_unbatched():
+@pytest.mark.parametrize("buckets", [(48,), (16, 32, 48)],
+                         ids=["one_bucket", "three_buckets"])
+@pytest.mark.parametrize("method", ["vanilla", "flash", "fused"])
+def test_scheduler_bit_identical_to_unbatched(method, buckets):
     """Regression for the padded-batch corruption bug: with an exact method,
     every scheduled request's path AND score must be bit-identical to an
     unbatched decode of its unpadded payload — bucket pad frames run as
-    tropical-identity steps, never as real DP transitions."""
+    tropical-identity steps, never as real DP transitions.  In either
+    bucket layout a later batch puts shorter rows into slots that longer
+    rows filled before, so the decoder must treat the stale tails of the
+    reused staging buffer as pad."""
     key = jax.random.key(2)
     k1, _ = jax.random.split(key)
     hmm = erdos_renyi_hmm(k1, 32, edge_prob=0.4)
     head = make_alignment_head(hmm.log_pi, hmm.log_A,
-                               AlignmentConfig(method="fused"))
-    sched = BatchScheduler(head, max_batch=4, buckets=(48,))
+                               AlignmentConfig(method=method))
+    sched = BatchScheduler(head, max_batch=4, buckets=buckets)
     rng = np.random.default_rng(1)
-    lens = [48, 20, 33, 1, 48]
+    lens = [48, 47, 16, 48, 15, 40, 33, 9, 35, 1, 3, 20]
     reqs = [sched.submit(rng.standard_normal((t, 32)).astype(np.float32))
             for t in lens]
     done = sched.drain()
-    assert len(done) == len(lens)
+    assert len(done) == len(lens) and all(r.done for r in reqs)
+    assert sched.stats["staging_reuses"] > 0
     for r in done:
         em = jnp.asarray(r.payload)
         opt_path, opt_score = viterbi_vanilla(hmm.log_pi, hmm.log_A, em)
         assert np.array_equal(r.result[0], np.asarray(opt_path))
-        assert np.isclose(r.result[1], float(opt_score), rtol=1e-6, atol=0)
+        assert np.float32(r.result[1]).tobytes() == \
+            np.float32(opt_score).tobytes()
