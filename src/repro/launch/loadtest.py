@@ -531,11 +531,12 @@ def drill_worker_death(cfg: LoadConfig, ckpt_dir: str | None = None, *,
 
     Two simulated workers alternate offline batches, beating a
     ``HeartbeatMonitor`` driven by the virtual clock, and a done-mask
-    checkpoint is written after every delivered batch.  At ``kill_batch`` the
-    active worker dies *after* the scheduler popped its batch (those requests
-    are in-flight on a dead host: gone).  The survivor notices the missed
-    heartbeats, restores the latest checkpoint, resubmits exactly the
-    requests the checkpoint does not cover, and drains.  Pass conditions:
+    checkpoint is written after every delivered batch.  At step
+    ``kill_batch`` the active worker dies with the batches the scheduler
+    has in flight (those requests are on a dead host: gone).  The survivor
+    notices the missed heartbeats, restores the latest checkpoint,
+    resubmits exactly the requests the checkpoint does not cover, and
+    drains.  Pass conditions:
     the dead worker is detected, every request is delivered exactly once,
     and every path is bit-identical to the oracle.
     """
@@ -559,14 +560,16 @@ def drill_worker_death(cfg: LoadConfig, ckpt_dir: str | None = None, *,
     duplicates = 0
     box = {"batch": 0, "die_at": kill_batch}
 
-    def flaky_head(em, lengths=None):
+    # the death comes at a step, not at a decode call: the scheduler may
+    # have dispatched a step's batch in the step before
+    def flaky_step(sched):
         if box["die_at"] is not None and box["batch"] == box["die_at"]:
             box["die_at"] = None
             raise WorkerDied("node hosting the in-flight batch lost")
-        return head(em, lengths)
+        return sched.step()
 
-    def fresh_sched(rids, fn):
-        sched = BatchScheduler(fn, max_batch=cfg.max_batch,
+    def fresh_sched(rids):
+        sched = BatchScheduler(head, max_batch=cfg.max_batch,
                                buckets=cfg.buckets)
         rid_of = {}
         for rid in rids:
@@ -574,14 +577,14 @@ def drill_worker_death(cfg: LoadConfig, ckpt_dir: str | None = None, *,
             rid_of[req.rid] = rid
         return sched, rid_of
 
-    sched, rid_of = fresh_sched(range(N), flaky_head)
+    sched, rid_of = fresh_sched(range(N))
     detected: list[int] = []
     restored_step = None
     resubmitted = 0
     while sched.queue:
         worker = box["batch"] % 2
         try:
-            completed = sched.step()
+            completed = flaky_step(sched)
         except WorkerDied:
             # the dead worker stops beating; the survivor keeps beating while
             # the monitor's timeout runs down on the virtual clock
@@ -603,7 +606,7 @@ def drill_worker_death(cfg: LoadConfig, ckpt_dir: str | None = None, *,
                 known_done = np.zeros((N,), np.bool_)
             todo = [rid for rid in range(N) if not known_done[rid]]
             resubmitted = len(todo)
-            sched, rid_of = fresh_sched(todo, head)
+            sched, rid_of = fresh_sched(todo)
             continue
         box["batch"] += 1
         mon.beat(worker)
@@ -638,11 +641,11 @@ def drill_mesh_rescale(cfg: LoadConfig, *, from_devices: int = 4,
 
     The first half of the trace decodes sharded over a ``from_devices``-wide
     data mesh.  The rescale is then *planned* against an
-    ``abstract_target_mesh`` (the login-host guard — no devices touched), the
-    in-flight queue migrates to a fresh scheduler on the shrunken mesh, and
-    the rest drains there.  A probe batch decoded on both meshes pins
-    bit-identity across the boundary; the oracle covers every request from
-    both phases.
+    ``abstract_target_mesh`` (the login-host guard — no devices touched),
+    the batches in flight finish on the wide mesh, the queue migrates to a
+    fresh scheduler on the shrunken mesh, and the rest drains there.  A
+    probe batch decoded on both meshes pins bit-identity across the
+    boundary; the oracle covers every request from both phases.
     """
     from jax.sharding import PartitionSpec as P
     from repro.checkpointing.elastic import abstract_target_mesh, plan_rescale
@@ -690,6 +693,7 @@ def drill_mesh_rescale(cfg: LoadConfig, *, from_devices: int = 4,
     rid_of = submit_all(sched, range(N))
     while sched.queue and len(delivered) < N // 2:
         deliver(sched.step(), rid_of)
+    deliver(sched.flush(), rid_of)     # what is in flight finishes here
     phase1 = len(delivered)
 
     # plan the shrink against an abstract target before committing to it

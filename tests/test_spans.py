@@ -29,7 +29,7 @@ STEP_SPANS = ("repro.inflight.stage", "repro.inflight.dispatch",
 
 def _recorded(tmp_path, fn):
     """Run `fn` under a profiler session; its ``repro.*`` host events as
-    (name, metadata, start_ns), in order of start."""
+    (name, metadata, start_ns, end_ns), in order of start."""
     from jax.profiler import ProfileData
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -43,7 +43,7 @@ def _recorded(tmp_path, fn):
             for ev in line.events:
                 if ev.name.startswith("repro."):
                     out.append((ev.name, {k: v for k, v in ev.stats},
-                                ev.start_ns))
+                                ev.start_ns, ev.end_ns))
     return sorted(out, key=lambda x: x[2])
 
 
@@ -85,6 +85,30 @@ def test_batch_scheduler_opens_four_spans_per_batch_in_order(tmp_path):
         names = [e[0] for e in events if e[1].get("batch") == n]
         assert names == list(BATCH_SPANS), (n, names)
     assert len(events) == 4 * 3
+
+
+def test_lookahead_batch_pads_and_dispatches_inside_its_ahead_span(
+        tmp_path):
+    # six rows of one bucket, batch 2: batch 1 is dispatched while batch 0
+    # is in flight, batch 2 after both are back
+    sched = BatchScheduler(_decode, max_batch=2, buckets=(8,))
+    for T in (5, 7, 3, 8, 1, 6):
+        sched.submit(np.ones((T, 4), np.float32))
+    _decode(np.zeros((2, 8, 4), np.float32), np.zeros(2, np.int32))
+    events = _recorded(tmp_path, sched.drain)
+    assert sched.stats["lookahead"] == 1
+    for n in range(3):
+        names = [e[0] for e in events
+                 if e[1].get("batch") == n and e[0] in BATCH_SPANS]
+        assert names == list(BATCH_SPANS), (n, names)
+    (ahead,) = [e for e in events if e[0] == "repro.batch.ahead"]
+    assert ahead[1]["batch"] == 1
+    inside = [e[0] for e in events
+              if ahead[2] <= e[2] and e[3] <= ahead[3] and e is not ahead]
+    assert inside == ["repro.batch.pad", "repro.batch.dispatch"]
+    wait0 = next(e for e in events
+                 if e[0] == "repro.batch.wait" and e[1]["batch"] == 0)
+    assert ahead[3] <= wait0[2]
 
 
 def test_inflight_step_spans_once_per_step_and_flush_per_finish(
